@@ -42,7 +42,6 @@ EPOCH_EXCLUDE = frozenset({
     "RACON_TPU_CACHE_MB",
     "RACON_TPU_CACHE_PERSIST",
     "RACON_TPU_CACHE_DIR",
-    "RACON_TPU_XLA_CACHE_DIR",
     # observability planes (pinned byte-identical on/off)
     "RACON_TPU_TRACE",
     "RACON_TPU_METRICS_JSON",

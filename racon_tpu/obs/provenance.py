@@ -34,7 +34,7 @@ KNOWN_KNOBS = {
     "RACON_TPU_POA_BATCH": "0",
     "RACON_TPU_POA_SWIN": "",
     "RACON_TPU_POA_KRANK": "",
-    "RACON_TPU_ALIGN_BUDGET": str(2 << 30),
+    "RACON_TPU_ALIGN_BUDGET": str(4 << 30),
     "RACON_TPU_MAX_ALIGN_DIM": "16384",
     "RACON_TPU_WFA": "1",
     "RACON_TPU_WFA_EMAX": "2048",
@@ -53,7 +53,6 @@ KNOWN_KNOBS = {
     "RACON_TPU_BP_COLS": "4000000",
     "RACON_TPU_POA_HOST_RESERVE": "0.25",
     "RACON_TPU_CACHE_DIR": "",
-    "RACON_TPU_XLA_CACHE_DIR": "",
     "RACON_TPU_TRACE": "",
     "RACON_TPU_METRICS_JSON": "",
     # serving (racon_tpu/serve): queue bound, worker count, idle

@@ -6,12 +6,16 @@ consensus engine.  Calls release the GIL, so the Polisher's thread pool
 achieves real parallelism on the CPU fallback path, mirroring the
 reference's per-thread spoa engines (src/polisher.cpp:180-184,490-503).
 
-The library is built on demand with `make` the first time it is needed.
+The library is built on demand with `make` the first time it is needed,
+and rebuilt whenever its tracked sources or the host's -march target
+change (``build_stamp``).
 """
 
 from __future__ import annotations
 
 import ctypes
+import fcntl
+import hashlib
 import os
 import subprocess
 import threading
@@ -34,6 +38,56 @@ _lib = None
 _lib_lock = threading.Lock()
 
 
+# every tracked input of the native build: a change to any of them,
+# or to the host CPU the -march=native code targets, rebuilds
+_BUILD_INPUTS = ("Makefile", "align.cpp", "poa.cpp", "poa_batch.cpp",
+                 "poa_graph.hpp")
+
+
+def _stamp_path() -> str:
+    return os.path.join(_NATIVE_DIR, "libracon_native.stamp")
+
+
+def _march() -> str:
+    """The target ``-march=native`` resolves to on this host (a
+    library copied from another machine may use instructions this one
+    lacks)."""
+    cxx = os.environ.get("CXX", "g++")
+    try:
+        out = subprocess.run(
+            [cxx, "-march=native", "-Q", "--help=target"],
+            capture_output=True, text=True, timeout=60).stdout
+    except (OSError, subprocess.SubprocessError):
+        return "unknown"
+    for line in out.splitlines():
+        parts = line.split()
+        if len(parts) == 2 and parts[0] == "-march=":
+            return parts[1]
+    return "unknown"
+
+
+def build_stamp() -> str:
+    """Hash of the tracked native sources plus the host's -march
+    target: the library is current only when its stamp matches."""
+    h = hashlib.sha256()
+    for name in _BUILD_INPUTS:
+        h.update(name.encode())
+        with open(os.path.join(_NATIVE_DIR, name), "rb") as f:
+            h.update(f.read())
+    h.update(_march().encode())
+    return h.hexdigest()
+
+
+def _needs_build(stamp: str) -> bool:
+    if not os.path.exists(_lib_path()):
+        return True
+    try:
+        with open(_stamp_path()) as f:
+            return f.read().strip() != stamp
+    except OSError:
+        return True
+
+
 def _build_library() -> None:
     lib_path = _lib_path()
     if "RACON_TPU_NATIVE_LIB" in os.environ:
@@ -42,17 +96,23 @@ def _build_library() -> None:
                 f"[racon_tpu::native] RACON_TPU_NATIVE_LIB points at a "
                 f"missing library: {lib_path}")
         return
-    sources = [os.path.join(_NATIVE_DIR, s)
-               for s in ("align.cpp", "poa.cpp")]
-    if os.path.exists(lib_path) and all(
-            os.path.getmtime(lib_path) >= os.path.getmtime(s)
-            for s in sources):
+    stamp = build_stamp()
+    if not _needs_build(stamp):
         return
-    proc = subprocess.run(["make", "-C", _NATIVE_DIR, "-j"],
-                          capture_output=True, text=True)
-    if proc.returncode != 0:
-        raise RuntimeError(
-            "[racon_tpu::native] build failed:\n" + proc.stderr)
+    # one builder at a time across processes (pytest-xdist workers
+    # all reach here on a fresh checkout); -B rebuilds every object,
+    # since objects copied from another host look up to date to make
+    with open(os.path.join(_NATIVE_DIR, ".build.lock"), "w") as lock:
+        fcntl.flock(lock, fcntl.LOCK_EX)
+        if not _needs_build(stamp):
+            return
+        proc = subprocess.run(["make", "-B", "-C", _NATIVE_DIR, "-j"],
+                              capture_output=True, text=True)
+        if proc.returncode != 0:
+            raise RuntimeError(
+                "[racon_tpu::native] build failed:\n" + proc.stderr)
+        with open(_stamp_path(), "w") as f:
+            f.write(stamp + "\n")
 
 
 def get_library() -> ctypes.CDLL:

@@ -17,21 +17,15 @@ from typing import Optional
 import numpy as np
 
 import jax
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
-
-try:
-    from jax import shard_map
-except ImportError:  # older jax
-    from jax.experimental.shard_map import shard_map
 
 
 def interpret_mode() -> bool:
     """Pallas kernels run in interpret mode on non-TPU backends (the
-    CPU dryrun mesh and the sharding tests)."""
-    try:
-        return jax.devices()[0].platform != "tpu"
-    except Exception:
-        return True
+    CPU dryrun mesh and the sharding tests).  A backend that fails to
+    start raises here: it must not quietly select interpret mode."""
+    return jax.devices()[0].platform != "tpu"
 
 
 def shard_batch_map(fn, mesh: Mesh, n_in: int, n_out: int):
@@ -40,12 +34,8 @@ def shard_batch_map(fn, mesh: Mesh, n_in: int, n_out: int):
     out_shapes carry no vma annotation)."""
     spec = P("batch")
     out = spec if n_out == 1 else (spec,) * n_out
-    try:
-        return shard_map(fn, mesh=mesh, in_specs=(spec,) * n_in,
-                         out_specs=out, check_vma=False)
-    except TypeError:  # older jax spells it check_rep
-        return shard_map(fn, mesh=mesh, in_specs=(spec,) * n_in,
-                         out_specs=out, check_rep=False)
+    return shard_map(fn, mesh=mesh, in_specs=(spec,) * n_in,
+                     out_specs=out, check_vma=False)
 
 
 def default_mesh(max_devices: Optional[int] = None) -> Mesh:

@@ -1,19 +1,17 @@
-"""Persistent XLA compilation cache.
+"""Persistent XLA compilation cache, and the racon_tpu cache root.
 
-The device kernels are lax.scan programs whose first compile costs
-seconds (a handful of bucket shapes x ~2.5 s each); the reference's
-CUDA kernels are precompiled at build time so it pays this cost never.
-Enabling jax's persistent compilation cache amortises our compiles
-across processes/runs the same way (first run pays, every later run --
-including every bench invocation -- loads from disk).
+The device kernels' first compile costs seconds per shape; the
+reference's CUDA kernels are precompiled at build time so it pays this
+cost never.  JAX's persistent compilation cache amortises our compiles
+across processes the same way (first run pays, every later run loads
+from disk).
 
-Override the location with RACON_TPU_CACHE_DIR; set it empty to
-disable.  RACON_TPU_XLA_CACHE_DIR overrides the XLA cache directory
-ALONE (empty = XLA cache off), without moving the result cache, the
-AOT shelf or calibration: a fleet of daemons with isolated result
-caches — or a test harness sandboxing RACON_TPU_CACHE_DIR per case —
-can still share one warm kernel cache, because compiled executables
-are keyed by HLO + compile options and can never change bytes.
+The XLA cache goes where ``JAX_COMPILATION_CACHE_DIR`` says, when it is
+set, and otherwise to ``<checkout>/.jax_cache`` (gitignored).  A fixed
+path matters: the path is part of the cache's key, so a directory that
+moves never hits.  ``RACON_TPU_CACHE_DIR`` names the root of racon's
+own caches (result cache, AOT shelf, calibration); it does not move
+the XLA cache.
 """
 
 from __future__ import annotations
@@ -24,11 +22,11 @@ _enabled = False
 
 
 def cache_root():
-    """The racon_tpu cache ROOT directory (holding the xla/, aot/
-    subdirs and calibration.json), honoring RACON_TPU_CACHE_DIR: unset
-    -> ~/.cache/racon_tpu, empty (or unexpanded '~' when HOME is
+    """The racon_tpu cache ROOT directory (holding the aot/ subdir,
+    results/ and calibration.json), honoring RACON_TPU_CACHE_DIR:
+    unset -> ~/.cache/racon_tpu, empty (or unexpanded '~' when HOME is
     unset) -> None = caching disabled.  A custom value names the root
-    itself; the XLA cache lives in its xla/ subdirectory."""
+    itself."""
     path = os.environ.get(
         "RACON_TPU_CACHE_DIR",
         os.path.join(os.path.expanduser("~"), ".cache", "racon_tpu"))
@@ -37,28 +35,31 @@ def cache_root():
     return path.rstrip("/") or None
 
 
+_CHECKOUT = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
+
+
+def compilation_cache_dir() -> str:
+    """``JAX_COMPILATION_CACHE_DIR`` when set, else the fixed in-checkout
+    path ``<checkout>/.jax_cache``."""
+    return (os.environ.get("JAX_COMPILATION_CACHE_DIR")
+            or os.path.join(_CHECKOUT, ".jax_cache"))
+
+
 def enable_compilation_cache() -> None:
     global _enabled
     if _enabled:
         return
     _enabled = True
-    override = os.environ.get("RACON_TPU_XLA_CACHE_DIR")
-    if override is not None:
-        if not override:
-            return
-        path = override
-    else:
-        root = cache_root()
-        if root is None:  # HOME unset -> literal "~", or explicit
-            return        # empty
-        path = os.path.join(root, "xla")
+    path = compilation_cache_dir()
     import jax
 
     try:
         os.makedirs(path, exist_ok=True)
-        jax.config.update("jax_compilation_cache_dir", path)
-        jax.config.update("jax_persistent_cache_min_compile_time_secs",
-                          0.5)
-        jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
     except OSError:
-        pass  # cache is an optimization; never fail the run for it
+        return  # cache is an optimization; never fail the run for it
+    # set even when it came from the environment: jax reads the
+    # variable once, at import, which may precede the caller's setenv
+    jax.config.update("jax_compilation_cache_dir", path)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.5)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)

@@ -103,6 +103,38 @@ def test_pipeline_timing_jitter_cannot_move_bytes(dataset,
         "jittered pipeline is not run-to-run deterministic")
 
 
+@pytest.mark.parametrize("pipeline", ["1", "0"])
+def test_device_collect_error_fails_the_run(dataset, monkeypatch,
+                                            pipeline):
+    """A POA megabatch whose collect raises fails the polish: its
+    windows are never quietly re-done on the CPU lane.  With the
+    pipeline on only the speculative consumer's megabatches fail."""
+    import threading
+
+    from racon_tpu.tpu import poa as tpu_poa
+
+    orig = tpu_poa.TPUPoaBatchEngine.consensus_batch_async
+
+    def broken(self, windows, trim, pool=None):
+        if pipeline == "1" and \
+                threading.current_thread().name != "racon-poa-stream":
+            return orig(self, windows, trim, pool)
+
+        def collect():
+            raise RuntimeError("device megabatch failed")
+        return collect
+
+    monkeypatch.setattr(tpu_poa.TPUPoaBatchEngine,
+                        "consensus_batch_async", broken)
+    with pytest.raises(RuntimeError, match="device megabatch failed"):
+        _polish_bytes(dataset, {
+            "RACON_TPU_PIPELINE": pipeline,
+            "RACON_TPU_PIPE_MIN": "2",
+            "RACON_TPU_POA_MEGABATCH": "4",
+            "RACON_TPU_CACHE": "0",
+        })
+
+
 def test_tracing_enabled_cannot_move_bytes(dataset, staged_bytes,
                                            tmp_path):
     """Tracing enabled (RACON_TPU_TRACE) + pipeline on must still
