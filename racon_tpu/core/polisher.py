@@ -176,7 +176,10 @@ class Polisher:
             print("[racon_tpu::Polisher::initialize] warning: object "
                   "already initialized!")
             return
+        with obs_trace.span("racon_tpu.initialize", cat="stage"):
+            self._initialize()
 
+    def _initialize(self) -> None:
         self.logger.log()
         # run-wall anchor for the derived host.share gauge (obs clock;
         # records only, never feeds control flow)
@@ -241,41 +244,39 @@ class Polisher:
         sequences_size = 0
         total_sequences_length = 0
         self.sparser.reset()
-        _t_seq = obs_trace.now()
-        while True:
-            chunk_start = len(self.sequences)
-            status = self.sparser.parse(self.sequences, CHUNK_SIZE)
-            kept: List[Sequence] = []
-            n_dropped = 0
-            for i in range(chunk_start, len(self.sequences)):
-                seq = self.sequences[i]
-                total_sequences_length += len(seq.data)
-                existing = name_to_id.get(seq.name + "t")
-                if existing is not None:
-                    if len(seq.data) != \
-                            len(self.sequences[existing].data) or \
-                            len(seq.quality) != \
-                            len(self.sequences[existing].quality):
-                        raise InvalidInputError(
-                            f"duplicate sequence {seq.name} with unequal "
-                            "data")
-                    name_to_id[seq.name + "q"] = existing
-                    id_to_id[sequences_size << 1 | 0] = existing
-                    n_dropped += 1
-                else:
-                    new_id = i - n_dropped
-                    name_to_id[seq.name + "q"] = new_id
-                    id_to_id[sequences_size << 1 | 0] = new_id
-                    kept.append(seq)
-                sequences_size += 1
-            del self.sequences[chunk_start:]
-            self.sequences.extend(kept)
-            if not status:
-                break
-        _t_seq_end = obs_trace.now()
-        obs_trace.TRACER.add_span("racon_tpu.load_sequences", _t_seq,
-                                  _t_seq_end, cat="stage")
-        self.metrics.add("host.parse_s", _t_seq_end - _t_seq)
+        with obs_trace.span("racon_tpu.load_sequences", cat="stage",
+                            metric="host.parse_s",
+                            registry=self.metrics):
+            while True:
+                chunk_start = len(self.sequences)
+                status = self.sparser.parse(self.sequences, CHUNK_SIZE)
+                kept: List[Sequence] = []
+                n_dropped = 0
+                for i in range(chunk_start, len(self.sequences)):
+                    seq = self.sequences[i]
+                    total_sequences_length += len(seq.data)
+                    existing = name_to_id.get(seq.name + "t")
+                    if existing is not None:
+                        if len(seq.data) != \
+                                len(self.sequences[existing].data) or \
+                                len(seq.quality) != \
+                                len(self.sequences[existing].quality):
+                            raise InvalidInputError(
+                                f"duplicate sequence {seq.name} with "
+                                "unequal data")
+                        name_to_id[seq.name + "q"] = existing
+                        id_to_id[sequences_size << 1 | 0] = existing
+                        n_dropped += 1
+                    else:
+                        new_id = i - n_dropped
+                        name_to_id[seq.name + "q"] = new_id
+                        id_to_id[sequences_size << 1 | 0] = new_id
+                        kept.append(seq)
+                    sequences_size += 1
+                del self.sequences[chunk_start:]
+                self.sequences.extend(kept)
+                if not status:
+                    break
 
         if sequences_size == 0:
             raise InvalidInputError("empty sequences set!")
@@ -707,6 +708,10 @@ class Polisher:
             "[racon_tpu::Polisher::polish] generated consensus")
 
     def polish(self, drop_unpolished_sequences: bool) -> List[Sequence]:
+        with obs_trace.span("racon_tpu.polish", cat="stage"):
+            return self._polish(drop_unpolished_sequences)
+
+    def _polish(self, drop_unpolished_sequences: bool) -> List[Sequence]:
         self.logger.log()
         with obs_trace.span("racon_tpu.consensus_stage", cat="stage",
                             metric="stage_wall_s.consensus",
@@ -741,7 +746,9 @@ class Polisher:
             return Sequence(self.sequences[window.id].name + tags,
                             polished_data)
 
-        with self.metrics.timer("host.stitch_s"):
+        with obs_trace.span("racon_tpu.stitch", cat="stage",
+                            metric="host.stitch_s",
+                            registry=self.metrics):
             if len(groups) > 1 and self.num_threads > 1:
                 stitched = list(self._pool.map(stitch, groups))
             else:

@@ -8,11 +8,14 @@ dispatch) whose timing story needs first-class tooling:
 
 * :mod:`racon_tpu.obs.trace` — a thread-safe span tracer emitting
   **Chrome trace-event JSON** (loadable in Perfetto /
-  ``chrome://tracing``).  Spans are nested per thread (stage → rung →
-  megabatch → chunk) and device dispatches get their own virtual
-  "device" lanes fed by the watcher threads.  Device-stage spans also
-  enter ``jax.profiler.TraceAnnotation`` so a jax/Perfetto device
-  profile correlates with the host spans by name.
+  ``chrome://tracing``).  Spans are nested per thread (stage → align
+  rung → pack/wait/decode, POA pack/wait/extract) and device
+  dispatches get their own virtual "device" lanes fed by the watcher
+  threads.  Every :func:`span` also enters
+  ``jax.profiler.TraceAnnotation``, so it lands in a concurrent JAX
+  profile on the profiler's clock and names the device's idle gaps;
+  pool-worker spans (CPU lanes, breaking-point decode) are kept to the
+  Chrome JSON.
 * :mod:`racon_tpu.obs.metrics` — a process-wide metrics registry
   (counters / gauges / histograms) that is the single source of truth
   for every number ``bench.py`` used to tally privately:
@@ -67,13 +70,13 @@ from racon_tpu.obs.devutil import DEVICE_UTIL, DeviceUtil
 from racon_tpu.obs.flight import FLIGHT, FlightRecorder
 from racon_tpu.obs.metrics import (HIST_BUCKETS, REGISTRY, MetricAttr,
                                    Registry, hist_quantile)
-from racon_tpu.obs.trace import (TRACER, device_span, enable_trace, now,
-                                 span, wall_now, write_trace)
+from racon_tpu.obs.trace import (TRACER, enable_trace, now, span,
+                                 wall_now, write_trace)
 
 __all__ = [
     "REGISTRY", "Registry", "MetricAttr", "TRACER",
     "HIST_BUCKETS", "hist_quantile", "DEVICE_UTIL", "DeviceUtil",
-    "now", "wall_now", "span", "device_span", "enable_trace",
+    "now", "wall_now", "span", "enable_trace",
     "write_trace",
     "JobContext", "job_context", "current", "jobs_for_tenant",
     "valid_trace_id", "FLIGHT", "FlightRecorder",

@@ -32,7 +32,6 @@ from __future__ import annotations
 
 import ctypes
 import functools
-import os
 import threading
 from typing import List, Optional, Tuple
 
@@ -569,44 +568,43 @@ class TPUPoaBatchEngine:
             max((len(ll) for ll in layer_lists), default=0) + 1, 8))
         b_pad = max(8, pow2_at_least(n, 8))
 
-        t0 = _mono()
-        seqs = np.zeros((b_pad, d1, lp), np.uint8)
-        wts = np.ones((b_pad, d1, lp), np.uint8)
-        meta = np.zeros((b_pad, d1, 8), np.int32)
-        nlay = np.zeros(b_pad, np.int32)
-        bblen = np.ones(b_pad, np.int32)
-        seqs[:, 0, 0] = ord("A")        # pad windows: 1-base backbone
-        host_fail = [False] * n
-        for b, w in enumerate(windows):
-            bb = w.sequences[0]
-            if len(bb) > min(lp, v):
-                host_fail[b] = True     # vcap analog, CPU re-polish
-                continue
-            bblen[b] = len(bb)
-            seqs[b, 0, :len(bb)] = np.frombuffer(bb, np.uint8)
-            q0 = w.qualities[0]
-            if q0:
-                wts[b, 0, :len(bb)] = \
-                    np.frombuffer(q0, np.uint8).astype(np.int32) \
-                    .clip(33, None).astype(np.uint8) - 33
-            offset = int(0.01 * len(bb))
-            nlay[b] = len(layer_lists[b])
-            for d, li in enumerate(layer_lists[b], start=1):
-                s = w.sequences[li]
-                seqs[b, d, :len(s)] = np.frombuffer(s, np.uint8)
-                ql = w.qualities[li]
-                if ql:
-                    wts[b, d, :len(s)] = \
-                        np.frombuffer(ql, np.uint8).astype(np.int32) \
+        with obs_trace.span("racon_tpu.poa_pack", cat="poa") as sp:
+            seqs = np.zeros((b_pad, d1, lp), np.uint8)
+            wts = np.ones((b_pad, d1, lp), np.uint8)
+            meta = np.zeros((b_pad, d1, 8), np.int32)
+            nlay = np.zeros(b_pad, np.int32)
+            bblen = np.ones(b_pad, np.int32)
+            seqs[:, 0, 0] = ord("A")        # pad windows: 1-base backbone
+            host_fail = [False] * n
+            for b, w in enumerate(windows):
+                bb = w.sequences[0]
+                if len(bb) > min(lp, v):
+                    host_fail[b] = True     # vcap analog, CPU re-polish
+                    continue
+                bblen[b] = len(bb)
+                seqs[b, 0, :len(bb)] = np.frombuffer(bb, np.uint8)
+                q0 = w.qualities[0]
+                if q0:
+                    wts[b, 0, :len(bb)] = \
+                        np.frombuffer(q0, np.uint8).astype(np.int32) \
                         .clip(33, None).astype(np.uint8) - 33
-                begin, end = w.positions[li]
-                full = 1 if (begin < offset
-                             and end > len(bb) - offset) else 0
-                meta[b, d, :4] = (begin, end, full, len(s))
+                offset = int(0.01 * len(bb))
+                nlay[b] = len(layer_lists[b])
+                for d, li in enumerate(layer_lists[b], start=1):
+                    s = w.sequences[li]
+                    seqs[b, d, :len(s)] = np.frombuffer(s, np.uint8)
+                    ql = w.qualities[li]
+                    if ql:
+                        wts[b, d, :len(s)] = \
+                            np.frombuffer(ql, np.uint8).astype(np.int32) \
+                            .clip(33, None).astype(np.uint8) - 33
+                    begin, end = w.positions[li]
+                    full = 1 if (begin < offset
+                                 and end > len(bb) - offset) else 0
+                    meta[b, d, :4] = (begin, end, full, len(s))
         with self._reject_lock:
-            self.phase_walls["export"] += _mono() - t0
+            self.phase_walls["export"] += sp.seconds
 
-        t_disp = _mono()
         handle = poa_pallas.poa_full_dispatch(
             seqs, wts, meta, nlay, bblen, v=v, lp=lp, d1=d1,
             p=self.pcap, s=self.pcap, a=8, k=self.kcap, wb=wb,
@@ -615,9 +613,9 @@ class TPUPoaBatchEngine:
             mesh=self.mesh)
 
         def collect():
-            t0 = _mono()
-            cons, mout = handle()
-            blocked = _mono() - t0
+            with obs_trace.span("racon_tpu.poa_wait", cat="poa") as sp:
+                cons, mout = handle()
+            blocked = sp.seconds
             # NOTE under the double-buffered pipeline: "dispatch"
             # counts only the UN-overlapped blocking residual (device
             # time hidden behind the next batch's packing shows up in
@@ -638,43 +636,35 @@ class TPUPoaBatchEngine:
                 # percentiles want the shape)
                 obs_metrics.REGISTRY.observe(
                     "poa_megabatch_device_s", dev_s)
-            if os.environ.get("RACON_TPU_POA_TRACE"):
-                import sys
-                live = nlay[:n][nlay[:n] > 0]
-                lo = int(live.min()) if live.size else 0
-                print(f"[poa-trace] b={n}(pad {b_pad}) d1={d1} "
-                      f"depths {lo}..{int(nlay[:n].max())} "
-                      f"span {_mono() - t_disp:.2f}s "
-                      f"blocked {blocked:.2f}s",
-                      file=sys.stderr, flush=True)
             with self._reject_lock:
                 self.n_rounds += 1
                 self.cells += int(mout[:n, 4].sum()) * wb
 
-            t1 = _mono()
-            results: List[Tuple[Optional[bytes], bool]] = []
-            code_map = {poa_pallas.FAIL_VCAP: -1,
-                        poa_pallas.FAIL_EDGE: -2,
-                        poa_pallas.FAIL_ALIGNED: -2,
-                        poa_pallas.FAIL_KCAP: -3,
-                        poa_pallas.FAIL_PATH: -3}
-            for b, w in enumerate(windows):
-                length = int(mout[b, 0])
-                if host_fail[b] or length < 0:
-                    code = code_map.get(int(mout[b, 2]), -1)
-                    with self._reject_lock:
-                        self.reject_counts[code] = \
-                            self.reject_counts.get(code, 0) + 1
-                    obs_decision.DECISIONS.record("poa_reject", code=code,
-                                     phase="extract")
-                    results.append((None, False))
-                    continue
-                if int(mout[b, 1]) == 2:
-                    w.warn_chimeric()
-                results.append(
-                    (bytes(cons[b, :length].astype(np.uint8)), True))
+            with obs_trace.span("racon_tpu.poa_extract", cat="poa") \
+                    as sp:
+                results: List[Tuple[Optional[bytes], bool]] = []
+                code_map = {poa_pallas.FAIL_VCAP: -1,
+                            poa_pallas.FAIL_EDGE: -2,
+                            poa_pallas.FAIL_ALIGNED: -2,
+                            poa_pallas.FAIL_KCAP: -3,
+                            poa_pallas.FAIL_PATH: -3}
+                for b, w in enumerate(windows):
+                    length = int(mout[b, 0])
+                    if host_fail[b] or length < 0:
+                        code = code_map.get(int(mout[b, 2]), -1)
+                        with self._reject_lock:
+                            self.reject_counts[code] = \
+                                self.reject_counts.get(code, 0) + 1
+                        obs_decision.DECISIONS.record("poa_reject", code=code,
+                                         phase="extract")
+                        results.append((None, False))
+                        continue
+                    if int(mout[b, 1]) == 2:
+                        w.warn_chimeric()
+                    results.append(
+                        (bytes(cons[b, :length].astype(np.uint8)), True))
             with self._reject_lock:
-                self.phase_walls["extract"] += _mono() - t1
+                self.phase_walls["extract"] += sp.seconds
             return results
 
         return collect
@@ -740,9 +730,9 @@ class TPUPoaBatchEngine:
                 seq_arr[i, :len(s)] = np.frombuffer(s, dtype=np.uint8)
                 slen[i] = len(s)
 
-            t0 = _mono()
-            _map(pool, export, active)
-            self.phase_walls["export"] += _mono() - t0
+            with obs_trace.span("racon_tpu.poa_pack", cat="poa") as sp:
+                _map(pool, export, active)
+            self.phase_walls["export"] += sp.seconds
             active = [i for i in active if not failed[i]]
             if not active:
                 continue
@@ -752,10 +742,10 @@ class TPUPoaBatchEngine:
             # (measured: compacting tail rounds to 32 lanes saved
             # nothing and the extra compiled shapes cost ~5s), so idle
             # lanes in late rounds ride along for free
-            t0 = _mono()
-            node_tape, seq_tape = self._dispatch(
-                bases, preds, nrows, sinks, seq_arr, slen)
-            self.phase_walls["dispatch"] += _mono() - t0
+            with obs_trace.span("racon_tpu.poa_wait", cat="poa") as sp:
+                node_tape, seq_tape = self._dispatch(
+                    bases, preds, nrows, sinks, seq_arr, slen)
+            self.phase_walls["dispatch"] += sp.seconds
             self.n_rounds += 1
 
             def apply(i):
@@ -811,9 +801,9 @@ class TPUPoaBatchEngine:
                 windows[i].warn_chimeric()
             results[i] = (out.raw[:length], True)
 
-        t0 = _mono()
-        _map(pool, extract, range(n))
-        self.phase_walls["extract"] += _mono() - t0
+        with obs_trace.span("racon_tpu.poa_extract", cat="poa") as sp:
+            _map(pool, extract, range(n))
+        self.phase_walls["extract"] += sp.seconds
         return results
 
     @staticmethod

@@ -259,6 +259,7 @@ class TPUPolisher(Polisher):
         self._align_device_free = threading.Event()
         self._poa_first_dispatch_t = None
         self._align_end_t = None
+        self._lead_in_noted = False
         self.pipeline_overlap_s = 0.0
         self.poa_spec_used = 0
         self.poa_spec_wasted = 0
@@ -469,15 +470,21 @@ class TPUPolisher(Polisher):
             reg = self._ledger._reg.get(id(o))
         return reg[0] if reg else 0
 
-    def _finish_overlap_batch(self, batch: List[Overlap]) -> None:
+    def _finish_overlap_batch(self, batch: List[Overlap],
+                              t_submit: float) -> None:
         """Pool task: decode a chunk's breaking points in ONE
         vectorized pass (core/overlap.decode_breaking_points_batch)
         while the device computes the next chunk, then advance the
         completion ledger for every member.  Replaces the pre-r7
         one-pool-task-per-overlap decode, whose per-record Python
-        CIGAR walk was the largest host stage on the mega bench."""
+        CIGAR walk was the largest host stage on the mega bench.
+        ``host.bp_decode_queue_s`` sums each batch's wait for a pool
+        thread (the CPU-lane workers share the pool)."""
+        self.metrics.add("host.bp_decode_queue_s", _now() - t_submit)
         try:
-            with self.metrics.timer("host.bp_decode_s"):
+            with obs_trace.span("racon_tpu.bp_decode", cat="host",
+                                metric="host.bp_decode_s",
+                                registry=self.metrics, profile=False):
                 overlap_mod.decode_breaking_points_batch(
                     batch, self.window_length)
         except Exception:
@@ -516,7 +523,7 @@ class TPUPolisher(Polisher):
             batch, self._decode_buf = self._decode_buf, []
             self._decode_buf_cols = 0
         self._decode_futs.append(
-            self._pool.submit(self._finish_overlap_batch, batch))
+            self._pool.submit(self._finish_overlap_batch, batch, _now()))
 
     def _stream_decode_flush(self) -> None:
         """Submit whatever the decode buffer holds (called at consume
@@ -527,8 +534,8 @@ class TPUPolisher(Polisher):
             batch, self._decode_buf = self._decode_buf, []
             self._decode_buf_cols = 0
         if batch:
-            self._decode_futs.append(
-                self._pool.submit(self._finish_overlap_batch, batch))
+            self._decode_futs.append(self._pool.submit(
+                self._finish_overlap_batch, batch, _now()))
 
     def _drain_stream_decodes(self) -> None:
         self._stream_decode_flush()
@@ -547,6 +554,22 @@ class TPUPolisher(Polisher):
     def _note_poa_dispatch(self) -> None:
         if self._poa_first_dispatch_t is None:
             self._poa_first_dispatch_t = _now()
+        self._note_device_dispatch()
+
+    def _note_device_dispatch(self) -> None:
+        """Gauge ``device.lead_in_s``: seconds from initialize()'s
+        start to the start of the run's first device dispatch (align
+        or POA, whichever thread gets there first): the host work the
+        device waits through before it is fed."""
+        if self._lead_in_noted:
+            return
+        with self._stream_lock:
+            if self._lead_in_noted:
+                return
+            self._lead_in_noted = True
+        t_start = getattr(self, "_t_run_start", None)
+        if t_start is not None:
+            self.metrics.set("device.lead_in_s", _now() - t_start)
 
     def _poa_consumer_loop(self) -> None:
         """Speculative POA consumer: while the align stage drains,
@@ -566,16 +589,12 @@ class TPUPolisher(Polisher):
 
         def collect_one():
             idxs, coll = inflight.pop(0)
-            t0 = _now()
             try:
                 for i, r in zip(idxs, coll()):
                     self._spec_results[i] = r
             except Exception as exc:
                 with self._stream_lock:
                     self._stream_errors.append(exc)
-            obs_trace.TRACER.add_span(
-                "poa.spec_megabatch_collect", t0, _now(), cat="poa",
-                args={"n": len(idxs)})
 
         while True:
             stop = self._consumer_stop
@@ -666,7 +685,7 @@ class TPUPolisher(Polisher):
         if self.tpu_poa_batches <= 0:
             return super().generate_consensuses()
         t0 = _now()
-        with obs_trace.device_span("racon_tpu.device_poa"):
+        with obs_trace.span("racon_tpu.device_poa", cat="device_stage"):
             flags = self._device_generate_consensuses()
         end = _now()
         start = t0
@@ -939,21 +958,23 @@ class TPUPolisher(Polisher):
             else None
 
         def cpu_worker():
-            while True:
-                with lock:
-                    if len(work) <= (0 if steal else dev_left):
-                        return
-                    i = work.pop()
-                t1 = _now()
-                flags[i], hit = self._consensus_cached(
-                    self.windows[i], _epoch)
-                if hit:
-                    # a cache lookup's wall says nothing about the
-                    # CPU engine rate: keep it out of the measurement
-                    continue
-                with lock:
-                    meas["cpu_w"] += _now() - t1
-                    meas["cpu_u"] += unit_of[i]
+            with obs_trace.span("racon_tpu.poa_cpu_lane", cat="poa",
+                                profile=False):
+                while True:
+                    with lock:
+                        if len(work) <= (0 if steal else dev_left):
+                            return
+                        i = work.pop()
+                    t1 = _now()
+                    flags[i], hit = self._consensus_cached(
+                        self.windows[i], _epoch)
+                    if hit:
+                        # a cache lookup's wall says nothing about the
+                        # CPU engine rate: keep it out of the measurement
+                        continue
+                    with lock:
+                        meas["cpu_w"] += _now() - t1
+                        meas["cpu_u"] += unit_of[i]
 
         workers = [self._pool.submit(cpu_worker)
                    for _ in range(n_workers)]
@@ -999,9 +1020,6 @@ class TPUPolisher(Polisher):
                     units=round(u_batch, 1),
                     predicted_s=round(pred, 6),
                     measured_s=round(now - mark, 6))
-            obs_trace.TRACER.add_span(
-                "poa.megabatch", mark, now, cat="poa",
-                args={"n": len(idxs), "recorded": bool(record)})
             mark = now
             ckpt = []
             for i, (cons, ok) in zip(idxs, results):
@@ -1216,10 +1234,10 @@ class TPUPolisher(Polisher):
         try:
             if self.tpu_aligner_batches > 0:
                 self._prewarm_poa_async(overlaps)
-                t0 = _now()
-                with obs_trace.device_span("racon_tpu.device_align"):
+                with obs_trace.span("racon_tpu.device_align",
+                                    cat="device_stage") as sp:
                     self._device_align_overlaps(overlaps)
-                self.stage_walls["device_align"] = _now() - t0
+                self.stage_walls["device_align"] = sp.seconds
                 self.metrics.set("stage_wall_s.device_align",
                                  self.stage_walls["device_align"])
             else:
@@ -1227,12 +1245,16 @@ class TPUPolisher(Polisher):
                 # may dispatch immediately and overlap the CPU align
                 self._mark_align_device_free()
             if self._pipeline_mode:
-                self._drain_stream_decodes()
+                with obs_trace.span("racon_tpu.bp_decode_drain",
+                                    cat="align"):
+                    self._drain_stream_decodes()
             # CPU path computes breaking points for everything, running
             # the CPU aligner only for overlaps still lacking a CIGAR
             # (cudapolisher.cpp:212-216); its per-overlap hook advances
             # the streaming ledger for anything not already notified
-            super().find_overlap_breaking_points(overlaps)
+            with obs_trace.span("racon_tpu.align_fallthrough",
+                                cat="align"):
+                super().find_overlap_breaking_points(overlaps)
         finally:
             # never leaves the consumer running on an error path; the
             # raise of any swallowed streaming error happens OUTSIDE
@@ -1365,7 +1387,10 @@ class TPUPolisher(Polisher):
         # the 4.0 ns/cell default this model predicts 6.8/14.7/25.8 ms
         # per pair at 10/15/20% divergence -- the measured values to
         # within 5%).
-        probe_ratio = self._probe_divergence(pending, cpu_ops)
+        with obs_trace.span("racon_tpu.align_probe", cat="align",
+                            metric="align.probe_s",
+                            registry=self.metrics):
+            probe_ratio = self._probe_divergence(pending, cpu_ops)
         ratio = min(max(probe_ratio, 0.05), 0.67)
         self.align_probe_ratio = ratio
         obs_decision.DECISIONS.record("align_probe", n_pending=len(pending),
@@ -1414,35 +1439,47 @@ class TPUPolisher(Polisher):
         work = deque(pending[cut:])
         lock = threading.Lock()
         n_cpu_done = 0
-        meas = {"cpu_w": 0.0, "cpu_u": 0.0}
+        t_split = _now()
+        meas = {"cpu_w": 0.0, "cpu_u": 0.0, "cpu_end": t_split}
 
         def cpu_worker():
             nonlocal n_cpu_done
-            while True:
-                with lock:
-                    if not work:
-                        return
-                    d, o = work.pop()
-                    n_cpu_done += 1
-                t1 = _now()
-                o.find_breaking_points(self.sequences,
-                                       self.window_length,
-                                       aligner=cpu_ops.align)
-                self._notify_overlap_done(o)
-                with lock:
-                    meas["cpu_w"] += _now() - t1
-                    meas["cpu_u"] += cpu_cells(float(d))
+            with obs_trace.span("racon_tpu.align_cpu_lane", cat="align",
+                                profile=False):
+                while True:
+                    with lock:
+                        if not work:
+                            return
+                        d, o = work.pop()
+                        n_cpu_done += 1
+                    t1 = _now()
+                    o.find_breaking_points(self.sequences,
+                                           self.window_length,
+                                           aligner=cpu_ops.align)
+                    self._notify_overlap_done(o)
+                    with lock:
+                        t2 = _now()
+                        meas["cpu_w"] += t2 - t1
+                        meas["cpu_u"] += cpu_cells(float(d))
+                        meas["cpu_end"] = max(meas["cpu_end"], t2)
 
         workers = [self._pool.submit(cpu_worker)
                    for _ in range(n_workers)]
         if cut:
             self._align_disp = []
             self._pallas_align([o for _, o in pending[:cut]])
+        t_dev_end = _now()
         # device share fully dispatched: speculative POA megabatches
         # may now queue behind it while the CPU workers drain
         self._mark_align_device_free()
-        for f in workers:
-            f.result()
+        with obs_trace.span("racon_tpu.align_lane_wait", cat="align"):
+            for f in workers:
+                f.result()
+        # when each lane finished its last pair, from the split: the
+        # lane that ends first waits for the other
+        self.metrics.set("align.device_lane_end_s", t_dev_end - t_split)
+        self.metrics.set("align.cpu_lane_end_s",
+                         meas["cpu_end"] - t_split)
         # the WFA-shaped CPU rate (ns per modeled cell) transfers
         # across workloads better than the old d^2 model because the
         # divergence enters through the probed ratio, not the rate;
@@ -1604,6 +1641,16 @@ class TPUPolisher(Polisher):
 
     _WFA_RUNGS = (512, 1024, 2048)
 
+    def _align_span(self, part: str):
+        """Span ``racon_tpu.align_<part>`` over one step of a rung's
+        dispatch loop, timed into ``align.<part>_s``: ``pack`` (cache
+        keying, encode, enqueue), ``wait`` (blocked on a chunk's
+        device results) or ``decode`` (tapes/moves to runs and the
+        breaking-point hand-off)."""
+        return obs_trace.span(f"racon_tpu.align_{part}", cat="align",
+                              metric=f"align.{part}_s",
+                              registry=self.metrics)
+
     def _pallas_align(self, overlaps: List[Overlap]) -> None:
         """Device alignment ladder (align_pallas kernels), cheapest
         engine first:
@@ -1650,6 +1697,7 @@ class TPUPolisher(Polisher):
                     for i in range(len(overlaps))]
         pending = list(range(len(overlaps)))
         n_dev = len(self.mesh.devices)
+        tenant = getattr(self, "_executor_tenant", None)
 
         wfa_cap = self._wfa_emax_cap()
         wfa_rungs = [e for e in self._WFA_RUNGS if e <= wfa_cap]
@@ -1706,58 +1754,59 @@ class TPUPolisher(Polisher):
                 # sliced results are byte-identical to a solo call)
                 from racon_tpu.tpu import executor
 
-                return executor.get_executor().align_wfa(
-                    [queries[i] for i in sub],
-                    [targets[i] for i in sub], bd, emax,
-                    mesh=self.mesh,
-                    tenant=getattr(self, "_executor_tenant", None))
+                self._note_device_dispatch()
+                with self._align_span("pack"):
+                    return executor.get_executor().align_wfa(
+                        [queries[i] for i in sub],
+                        [targets[i] for i in sub], bd, emax,
+                        mesh=self.mesh, tenant=tenant)
 
-            t_rung = _now()     # rung span start: chunk spans nest in
-            tally = {"cert": 0, "mark": t_rung}
+            tally = {"cert": 0, "mark": _now()}
             still = set()
             self.metrics.add(f"align_rung_admit.wfa{emax}", len(idx))
 
             def consume(sub, coll, emax=emax, tally=tally,
                         still=still):
-                tapes, nents, dists = coll()
-                dev_s = getattr(coll, "device_s", lambda: 0.0)()
-                self.align_device_s += dev_s
-                self.align_wfa_device_s += dev_s
-                if dev_s > 0:
-                    self.metrics.observe("align_chunk_device_s.wfa",
-                                         dev_s)
-                steps = float(sum(min(int(d), emax) for d in dists))
+                with self._align_span("wait"):
+                    tapes, nents, dists = coll()
                 now = _now()
-                obs_trace.TRACER.add_span(
-                    f"align.chunk.wfa{emax}", tally["mark"], now,
-                    cat="align", args={"n": len(sub)})
-                # chunks with cache-served lanes are excluded from
-                # the rate measurement: their wall covers fewer
-                # device steps than the unit count claims (r18)
-                if not getattr(coll, "cache_hits", 0) and \
-                        hasattr(self, "_align_disp"):
-                    self._align_disp.append(
-                        ("wfa", emax, now - tally["mark"], steps))
-                tally["mark"] = now
-                # e-steps actually run x diagonal extent = the honest
-                # cell count for a wavefront engine
-                self.align_cells += int(steps) * (2 * emax + 1)
-                for k, i in enumerate(sub):
-                    if int(dists[k]) <= emax:
-                        ops = align_pallas.wfa_tape_to_ops(
-                            tapes[k], int(nents[k]))
-                        overlaps[i].cigar_runs = \
-                            aligner.ops_to_runs(ops)
-                        self._stream_decode(overlaps[i])
-                        tally["cert"] += 1
-                    else:
-                        still.add(i)
-                self._stream_decode_flush()
+                with self._align_span("decode"):
+                    dev_s = getattr(coll, "device_s", lambda: 0.0)()
+                    self.align_device_s += dev_s
+                    self.align_wfa_device_s += dev_s
+                    if dev_s > 0:
+                        self.metrics.observe(
+                            "align_chunk_device_s.wfa", dev_s)
+                    steps = float(sum(min(int(d), emax)
+                                      for d in dists))
+                    # chunks with cache-served lanes are excluded from
+                    # the rate measurement: their wall covers fewer
+                    # device steps than the unit count claims (r18)
+                    if not getattr(coll, "cache_hits", 0) and \
+                            hasattr(self, "_align_disp"):
+                        self._align_disp.append(
+                            ("wfa", emax, now - tally["mark"], steps))
+                    tally["mark"] = now
+                    # e-steps actually run x diagonal extent = the
+                    # honest cell count for a wavefront engine
+                    self.align_cells += int(steps) * (2 * emax + 1)
+                    for k, i in enumerate(sub):
+                        if int(dists[k]) <= emax:
+                            ops = align_pallas.wfa_tape_to_ops(
+                                tapes[k], int(nents[k]))
+                            overlaps[i].cigar_runs = \
+                                aligner.ops_to_runs(ops)
+                            self._stream_decode(overlaps[i])
+                            tally["cert"] += 1
+                        else:
+                            still.add(i)
+                    self._stream_decode_flush()
 
-            align_pallas.run_pipelined(chunks, dispatch, consume)
-            obs_trace.TRACER.add_span(
-                f"align.rung.wfa{emax}", t_rung, _now(), cat="align",
-                args={"n": len(idx), "chunks": len(chunks)})
+            with obs_trace.span(
+                    "racon_tpu.align_rung", cat="align",
+                    args={"engine": "wfa", "rung": emax,
+                          "pairs": len(idx), "chunks": len(chunks)}):
+                align_pallas.run_pipelined(chunks, dispatch, consume)
             n_cert = tally["cert"]
             idx_set = set(idx)
             pending = [i for i in pending
@@ -1803,65 +1852,67 @@ class TPUPolisher(Polisher):
             def dispatch(sub, wb=wb):
                 from racon_tpu.tpu import executor
 
-                return executor.get_executor().align_band(
-                    [queries[i] for i in sub],
-                    [targets[i] for i in sub],
-                    bd, bd, wb, mesh=self.mesh,
-                    centers=[emp_knots(i) if i in use_emp else None
-                             for i in sub],
-                    tenant=getattr(self, "_executor_tenant", None))
+                self._note_device_dispatch()
+                with self._align_span("pack"):
+                    return executor.get_executor().align_band(
+                        [queries[i] for i in sub],
+                        [targets[i] for i in sub],
+                        bd, bd, wb, mesh=self.mesh,
+                        centers=[emp_knots(i) if i in use_emp
+                                 else None for i in sub],
+                        tenant=tenant)
 
-            t_rung = _now()     # rung span start: chunk spans nest in
-            tally = {"cert": 0, "mark": t_rung}
+            tally = {"cert": 0, "mark": _now()}
             still = set()
             self.metrics.add(f"align_rung_admit.band{wb}", len(idx))
 
             def consume(sub, coll, wb=wb, tally=tally, still=still):
-                moves, lens, dists = coll()
-                dev_s = getattr(coll, "device_s", lambda: 0.0)()
-                self.align_device_s += dev_s
-                self.align_band_device_s += dev_s
-                if dev_s > 0:
-                    self.metrics.observe("align_chunk_device_s.band",
-                                         dev_s)
+                with self._align_span("wait"):
+                    moves, lens, dists = coll()
                 now = _now()
-                obs_trace.TRACER.add_span(
-                    f"align.chunk.band{wb}", tally["mark"], now,
-                    cat="align", args={"n": len(sub)})
-                # cache-served lanes: same measurement exclusion as
-                # the wfa rung above (r18)
-                if not getattr(coll, "cache_hits", 0) and \
-                        hasattr(self, "_align_disp"):
-                    self._align_disp.append(
-                        ("band", wb, now - tally["mark"],
-                         float(sum(len(queries[i]) for i in sub))))
-                tally["mark"] = now
-                self.align_cells += sum(len(queries[i])
-                                        for i in sub) * wb
-                for k, i in enumerate(sub):
-                    if i in use_emp:
-                        ok = int(dists[k]) < align_pallas._BIG and \
-                            align_pallas.path_center_margin(
-                                moves[k], int(lens[k]), knots[i],
-                                wb) >= 256
-                    else:
-                        ok = dists[k] + dabs[i] <= wb - 512
-                    if ok:
-                        ops = align_pallas.moves_to_ops(
-                            moves[k], int(lens[k]), queries[i],
-                            targets[i])
-                        overlaps[i].cigar_runs = \
-                            aligner.ops_to_runs(ops)
-                        self._stream_decode(overlaps[i])
-                        tally["cert"] += 1
-                    else:
-                        still.add(i)
-                self._stream_decode_flush()
+                with self._align_span("decode"):
+                    dev_s = getattr(coll, "device_s", lambda: 0.0)()
+                    self.align_device_s += dev_s
+                    self.align_band_device_s += dev_s
+                    if dev_s > 0:
+                        self.metrics.observe(
+                            "align_chunk_device_s.band", dev_s)
+                    # cache-served lanes: same measurement exclusion
+                    # as the wfa rung above (r18)
+                    if not getattr(coll, "cache_hits", 0) and \
+                            hasattr(self, "_align_disp"):
+                        self._align_disp.append(
+                            ("band", wb, now - tally["mark"],
+                             float(sum(len(queries[i])
+                                       for i in sub))))
+                    tally["mark"] = now
+                    self.align_cells += sum(len(queries[i])
+                                            for i in sub) * wb
+                    for k, i in enumerate(sub):
+                        if i in use_emp:
+                            ok = int(dists[k]) < align_pallas._BIG \
+                                and align_pallas.path_center_margin(
+                                    moves[k], int(lens[k]), knots[i],
+                                    wb) >= 256
+                        else:
+                            ok = dists[k] + dabs[i] <= wb - 512
+                        if ok:
+                            ops = align_pallas.moves_to_ops(
+                                moves[k], int(lens[k]), queries[i],
+                                targets[i])
+                            overlaps[i].cigar_runs = \
+                                aligner.ops_to_runs(ops)
+                            self._stream_decode(overlaps[i])
+                            tally["cert"] += 1
+                        else:
+                            still.add(i)
+                    self._stream_decode_flush()
 
-            align_pallas.run_pipelined(chunks, dispatch, consume)
-            obs_trace.TRACER.add_span(
-                f"align.rung.band{wb}", t_rung, _now(), cat="align",
-                args={"n": len(idx), "chunks": len(chunks)})
+            with obs_trace.span(
+                    "racon_tpu.align_rung", cat="align",
+                    args={"engine": "band", "rung": wb,
+                          "pairs": len(idx), "chunks": len(chunks)}):
+                align_pallas.run_pipelined(chunks, dispatch, consume)
             n_cert = tally["cert"]
             idx_set = set(idx)
             pending = [i for i in pending
@@ -2020,6 +2071,7 @@ class TPUPolisher(Polisher):
         # (where the align_pallas watcher threads never run)
         runs_of: dict = {}
         if miss:
+            self._note_device_dispatch()
             t0 = _now()
             ops, cells, unresolved = aligner.band_align_batch(
                 [queries[i] for i in miss],

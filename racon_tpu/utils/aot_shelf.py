@@ -21,6 +21,8 @@ import hashlib
 import os
 import threading
 
+from racon_tpu.obs import trace as obs_trace
+
 _mem: dict = {}
 _salts: dict = {}
 _recorded: set = set()
@@ -157,7 +159,6 @@ def call(key_parts: tuple, src_file: str, build_fn, args: tuple):
         return build_fn(*args)
     _record_manifest(key_parts)
     import jax
-    from jax import export as jexport
 
     key = hashlib.sha1(
         repr((key_parts, _source_salt(src_file), _SHELF_VERSION,
@@ -175,6 +176,18 @@ def call(key_parts: tuple, src_file: str, build_fn, args: tuple):
             with _lock:
                 _mem[key] = build_fn
             return build_fn(*args)
+    # first contact in this process: a shelf load or an export, and
+    # the new jit's first call -- the span names a compile that lands
+    # inside a traced window
+    with obs_trace.span("racon_tpu.compile", cat="compile",
+                        args={"variant": "/".join(
+                            str(p) for p in key_parts)}):
+        return _first_contact(key, key_parts, build_fn, args)
+
+
+def _first_contact(key: str, key_parts: tuple, build_fn, args: tuple):
+    import jax
+    from jax import export as jexport
 
     path = os.path.join(_shelf_dir(), key + ".jexp")
     exp = None

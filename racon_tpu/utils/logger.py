@@ -22,8 +22,9 @@ One r14 addition: lines emitted under an active job context
 is attributable.  The format stays byte-identical when no context is
 active (one-shot CLI, library use, tests).
 
-Device-stage trace spans live at the dispatch sites
-(racon_tpu/tpu/polisher.py via racon_tpu.obs.device_span), the analog
+Stage trace spans live at the stage boundaries
+(racon_tpu/core/polisher.py, racon_tpu/tpu/polisher.py via
+racon_tpu.obs.span), the analog
 of the reference's nvprof ranges (src/cuda/cudapolisher.cpp:66-70).
 """
 

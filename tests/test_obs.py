@@ -314,6 +314,20 @@ def test_traced_polish_byte_identical_and_schema(obs_dataset,
     assert "racon_tpu.device_poa" in names
     assert "racon_tpu.align_stage" in names
     assert "racon_tpu.consensus_stage" in names
+    # live spans at the layer boundaries, none recorded after the
+    # fact (the POA engine's spans: tests/test_trace_spans.py; here
+    # the plain run's results fill the result cache first)
+    assert {"racon_tpu.initialize", "racon_tpu.load_sequences",
+            "racon_tpu.polish", "racon_tpu.stitch"} <= names
+    assert not any(n.startswith(("align.rung.", "align.chunk.",
+                                 "poa.spec_megabatch_collect"))
+                   for n in names)
+    # the stitch span and host.stitch_s are one timing
+    stitch_us = sum(ev["dur"] for ev in doc["traceEvents"]
+                    if ev.get("ph") == "X"
+                    and ev["name"] == "racon_tpu.stitch")
+    assert stitch_us * 1e-6 == pytest.approx(
+        pol.metrics.value("host.stitch_s"), rel=1e-6, abs=1e-6)
     obs_trace.TRACER.clear()
 
     # the run registry carries every pipeline health counter and the
