@@ -510,6 +510,7 @@ def main():
             "pipeline_overlap_s": round(overlap_s, 3),
             "poa_spec_used": int(m.value("poa_spec_used")),
             "poa_spec_wasted": int(m.value("poa_spec_wasted")),
+            "poa_spec_skipped": int(m.value("poa_spec_skipped")),
             "poa_spec_megabatches": int(
                 m.value("poa_spec_megabatches")),
             "ledger_ready_high_water": int(

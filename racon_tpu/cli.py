@@ -250,17 +250,19 @@ def parse_args(argv):
 
 def _log_run_summary(polisher, opts) -> None:
     """One-line end-of-run health summary at default verbosity: the
-    speculative-pipeline counters (adopted vs wasted speculation, the
-    ledger's ready-queue high-water mark) used to be visible only
-    inside bench runs; a production polish should say whether its
-    speculation paid off without re-running under bench.py."""
+    speculative-pipeline counters (adopted, wasted and skipped
+    speculation, the ledger's ready-queue high-water mark) used to be
+    visible only inside bench runs; a production polish should say
+    whether its speculation paid off without re-running under
+    bench.py."""
     m = getattr(polisher, "metrics", None)
     if m is None:
         return
     if opts["tpu_poa_batches"] > 0:
         print("[racon_tpu::] pipeline summary: "
               f"spec used {int(m.value('poa_spec_used'))}"
-              f"/wasted {int(m.value('poa_spec_wasted'))} window(s), "
+              f"/wasted {int(m.value('poa_spec_wasted'))}"
+              f"/skipped {int(m.value('poa_spec_skipped'))} window(s), "
               "ledger ready peak "
               f"{int(m.value('ledger_ready_high_water'))}, "
               f"overlap {float(m.value('pipeline_overlap_s')):.2f} s, "

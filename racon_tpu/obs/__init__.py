@@ -20,9 +20,9 @@ dispatch) whose timing story needs first-class tooling:
   (counters / gauges / histograms) that is the single source of truth
   for every number ``bench.py`` used to tally privately:
   ``poa_device_s``, ``align_wfa_device_s`` / ``align_band_device_s``,
-  ``pipeline_overlap_s``, ``poa_spec_used`` / ``poa_spec_wasted``,
-  AOT-shelf hit/miss/fallback, ladder rung admissions/retries, the
-  WindowLedger ready-queue high-water mark.  Each polisher owns a
+  ``pipeline_overlap_s``, ``poa_spec_used`` / ``poa_spec_wasted`` /
+  ``poa_spec_skipped``, AOT-shelf hit/miss/fallback, ladder rung
+  admissions/retries, the WindowLedger ready-queue high-water mark.  Each polisher owns a
   per-run child registry that propagates into the global one.
 * :mod:`racon_tpu.obs.provenance` — per-run environment provenance
   (resolved ``RACON_TPU_*`` knobs, jax backend, host-capability

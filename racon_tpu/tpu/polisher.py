@@ -248,6 +248,8 @@ class TPUPolisher(Polisher):
         self._poa_engine = None
         self._spec_results = {}
         self._spec_cap = 0
+        # speculate every window until _pipeline_begin predicts
+        self._spec_floor = (-1, 0)
         self._consumer = None
         self._consumer_stop = False
         self._decode_futs = []
@@ -351,6 +353,77 @@ class TPUPolisher(Polisher):
             return 0
         return max(0, self.num_threads - 1)
 
+    def _poa_unit(self, depth: int, length: int) -> float:
+        """A window's POA cost units, depth * (1 + depth/48) *
+        (len/500) at its depth capped to MAX_DEPTH_PER_WINDOW --
+        superlinear in depth because inserts grow the graph."""
+        depth = min(depth, self.MAX_DEPTH_PER_WINDOW)
+        return depth * (1 + depth / 48.0) * (length / 500.0)
+
+    def _poa_cut(self, depths, units, n_workers: int, steal: bool):
+        """The POA stage's device/CPU split over windows in its
+        depth-descending order: ``(cut, priced)``, the device taking
+        [0, cut).  ``depths`` are layer counts (sequences - 1),
+        ``units`` their :meth:`_poa_unit` costs; ``priced`` is
+        ``(r_dev, r_cpu, source, n_priced)`` when the rate model set
+        the cut, else None.  The stage and the speculative consumer's
+        prediction (:meth:`_spec_floor_key`) both cut here, so the
+        two cannot drift apart."""
+        from racon_tpu.utils import calibrate
+
+        if steal or not n_workers:
+            return len(depths), None     # device may reach everything
+        if "RACON_TPU_POA_SPLIT" in os.environ:
+            # manual device-share override (fraction of depth^2 weight)
+            share = float(os.environ["RACON_TPU_POA_SPLIT"])
+            return _split_cut([(d + 1) ** 2 for d in depths], share), None
+        # deterministic rate-model argmin (like the align stage) at
+        # SELF-CALIBRATED us/unit rates: measured on this machine by a
+        # previous run and persisted next to the XLA cache (defaults
+        # reflect the r6 kernel until then; env pins for golden CI
+        # configs) -- racon_tpu/utils/calibrate
+        n_dev = len(self.mesh.devices)
+        r_dev, r_cpu, r_src = calibrate.get_rates(
+            "poa", n_dev, self.POA_DEV_US_PER_UNIT,
+            self.POA_CPU_US_PER_UNIT, pin=self._calib_pin)
+        # price the CPU tail over the RESERVED-down worker count: the
+        # host also runs the data plane (decode, routing, stitching),
+        # so a full-worker rate overstated the tail and capped the
+        # device share (no-op under env-pinned rates, keeping golden
+        # configs byte-stable)
+        n_priced = calibrate.host_reserved_workers(n_workers, r_src)
+        cut = _rate_split([u * r_dev / n_dev for u in units],
+                          [u * r_cpu / n_priced for u in units])
+        return cut, (r_dev, r_cpu, r_src, n_priced)
+
+    def _spec_floor_key(self) -> tuple:
+        """The stage's own split (:meth:`_poa_cut`), predicted at the
+        ledger's seal from every window's registered overlap count --
+        an upper bound on its final layer count.  Returns the last
+        predicted device window's place in the stage's order as
+        ``(depth, -window_id)``: a ready window is device-bound when
+        its own ``(depth, -id)`` is at least that (deeper, or as deep
+        and earlier, as the stage's stable sort breaks ties).
+        ``(-1, 0)`` -- every window -- when the stage hands the device
+        everything: device-only, or RACON_TPU_STEAL."""
+        n_workers = self._tail_workers("RACON_TPU_POA_DEVICE_ONLY")
+        if not n_workers or os.environ.get("RACON_TPU_STEAL"):
+            return (-1, 0)
+        counts = self._ledger.pending.tolist()
+        # the stage's eligibility (>= 3 sequences) and stable
+        # depth-descending order, at the predicted depths
+        eligible = sorted((i for i, c in enumerate(counts) if c >= 2),
+                          key=lambda i: -counts[i])
+        depths = [counts[i] for i in eligible]
+        units = [self._poa_unit(d, len(self.windows[i].sequences[0]))
+                 for d, i in zip(depths, eligible)]
+        cut, _ = self._poa_cut(depths, units, n_workers, False)
+        if cut >= len(depths):
+            return (-1, 0)
+        if not cut:
+            return (float("inf"), 0)    # no predicted device window
+        return (depths[cut - 1], -eligible[cut - 1])
+
     # ------------------------------------------------------------------
     # streaming pipeline (cross-stage target/window streaming)
     # ------------------------------------------------------------------
@@ -389,7 +462,8 @@ class TPUPolisher(Polisher):
         create the window skeleton, register every overlap's window
         range with the completion ledger (per-target accounting at
         window granularity -- a single-contig polish still streams),
-        and start the speculative POA consumer."""
+        predict which windows the POA stage's split will hand the
+        device, and start the speculative POA consumer."""
         from racon_tpu.core.window import WindowLedger
 
         self._create_windows(self._targets_size, self.window_type)
@@ -406,6 +480,8 @@ class TPUPolisher(Polisher):
             self._ledger.register(id(o), idx, lo, hi)
         self._coverage_counted = True
         self._ledger.seal()
+        self._spec_floor = self._spec_floor_key()
+        self.metrics.add("poa_spec_skipped", 0)
         self._spec_results = {}
         self._decode_futs = []
         self._decode_buf = []
@@ -455,14 +531,23 @@ class TPUPolisher(Polisher):
         if not newly:
             return
         ready = []
+        skipped = 0
         for wid, wfrags in newly:
             win = self.windows[wid]
             for _, _, data, qual, begin, end in wfrags:
                 win.add_layer(data, qual, begin, end)
             # only device-eligible windows feed the consumer; trivial
-            # (<3 sequences) windows keep the backbone at stage time
-            if len(win.sequences) >= 3:
+            # (<3 sequences) windows keep the backbone at stage time,
+            # and windows past the predicted device share are left to
+            # the stage, whose split would recompute them on the CPU
+            if len(win.sequences) < 3:
+                continue
+            if (len(win.sequences) - 1, -wid) >= self._spec_floor:
                 ready.append(wid)
+            else:
+                skipped += 1
+        if skipped:
+            self.metrics.add("poa_spec_skipped", skipped)
         led.push_ready(ready)
 
     def _ledger_ordinal(self, o: Overlap) -> int:
@@ -578,7 +663,10 @@ class TPUPolisher(Polisher):
         window id; the stage later uses them only for windows the
         deterministic rate-model argmin assigns to the device (the
         rest are recomputed by the CPU engine exactly as in the staged
-        path), so speculation never reaches the output bytes."""
+        path), so speculation never reaches the output bytes.  The
+        ready queue holds only the windows the stage's split is
+        predicted to hand the device (_spec_floor_key), so what it
+        computes is what the stage adopts."""
         from racon_tpu.tpu import align_pallas as _ap
 
         led = self._ledger
@@ -606,9 +694,7 @@ class TPUPolisher(Polisher):
                 # taken -- there is no align time left to hide it in
                 take = led.pop_ready(self._spec_cap, min_take)
             if take:
-                # deepest-first: megabatch rounds drain uniformly and
-                # the deepest windows are the likeliest device
-                # assignees under the argmin (least speculation waste)
+                # deepest-first: megabatch rounds drain uniformly
                 take.sort(
                     key=lambda i: -len(self.windows[i].sequences))
                 batch = [self.windows[i] for i in take]
@@ -720,8 +806,12 @@ class TPUPolisher(Polisher):
         engine = self._poa_engine or self._make_poa_engine()
         self._poa_engine = None
         # speculative results from the align-stage consumer (empty
-        # when the pipeline is off or nothing became ready in time)
-        self._join_consumer()
+        # when the pipeline is off or nothing became ready in time);
+        # the CPU lane starts only after this wait
+        with obs_trace.span("racon_tpu.poa_spec_join", cat="poa",
+                            metric="poa.spec_join_s",
+                            registry=self.metrics):
+            self._join_consumer()
         with self._stream_lock:
             errs = list(self._stream_errors)
         if errs:
@@ -776,44 +866,16 @@ class TPUPolisher(Polisher):
         n_workers = self._tail_workers("RACON_TPU_POA_DEVICE_ONLY")
         steal = bool(os.environ.get("RACON_TPU_STEAL")) and n_workers
         work = deque(eligible)
-        # per-window cost units depth * (1 + depth/48) * (len/500) --
-        # superlinear in depth because inserts grow the graph -- feed
-        # both the split model and the in-run rate measurement
-        unit_of = {}
-        for i in eligible:
-            w0 = self.windows[i]
-            depth = min(len(w0.sequences) - 1,
-                        self.MAX_DEPTH_PER_WINDOW)
-            unit_of[i] = depth * (1 + depth / 48.0) \
-                * (len(w0.sequences[0]) / 500.0)
+        # per-window cost units feed both the split model and the
+        # in-run rate measurement
+        depths = [len(self.windows[i].sequences) - 1 for i in eligible]
+        units = [self._poa_unit(d, len(self.windows[i].sequences[0]))
+                 for d, i in zip(depths, eligible)]
+        unit_of = dict(zip(eligible, units))
         meas = {"dev": [], "cpu_w": 0.0, "cpu_u": 0.0}
-        if steal or not n_workers:
-            dev_left = len(eligible)     # device may reach everything
-        elif "RACON_TPU_POA_SPLIT" in os.environ:
-            # manual device-share override (fraction of depth^2 weight)
-            dev_left = _split_cut(
-                [len(self.windows[i].sequences) ** 2
-                 for i in eligible],
-                float(os.environ["RACON_TPU_POA_SPLIT"]))
-        else:
-            # deterministic rate-model argmin (like the align stage)
-            # at SELF-CALIBRATED us/unit rates: measured on this
-            # machine by a previous run and persisted next to the XLA
-            # cache (defaults reflect the r6 kernel until then; env
-            # pins for golden CI configs) -- racon_tpu/utils/calibrate
-            r_dev, r_cpu, r_src = calibrate.get_rates(
-                "poa", n_dev, self.POA_DEV_US_PER_UNIT,
-                self.POA_CPU_US_PER_UNIT, pin=self._calib_pin)
-            # price the CPU tail over the RESERVED-down worker count:
-            # the host also runs the data plane (decode, routing,
-            # stitching), so a full-worker rate overstated the tail
-            # and capped the device share (no-op under env-pinned
-            # rates, keeping golden configs byte-stable)
-            n_priced = calibrate.host_reserved_workers(n_workers,
-                                                       r_src)
-            dev_left = _rate_split(
-                [unit_of[i] * r_dev / n_dev for i in eligible],
-                [unit_of[i] * r_cpu / n_priced for i in eligible])
+        dev_left, priced = self._poa_cut(depths, units, n_workers, steal)
+        if priced is not None:
+            r_dev, r_cpu, r_src, n_priced = priced
             self.logger.log(
                 f"[racon_tpu::TPUPolisher::polish] poa split: device "
                 f"{dev_left}/{len(eligible)} windows "
@@ -828,8 +890,6 @@ class TPUPolisher(Polisher):
         sd_dev, sd_cpu, sd_src = calibrate.get_rates(
             "poa", n_dev, self.POA_DEV_US_PER_UNIT,
             self.POA_CPU_US_PER_UNIT, pin=self._calib_pin)
-        units = [unit_of[i] for i in eligible]
-        depths = [len(self.windows[i].sequences) - 1 for i in eligible]
         total_u = sum(units) or 1.0
 
         def _q(v, q):
@@ -908,7 +968,9 @@ class TPUPolisher(Polisher):
                 f"[racon_tpu::TPUPolisher::polish] poa stream: "
                 f"{self.poa_spec_used}/{len(spec)} speculative "
                 f"window(s) adopted "
-                f"({self.poa_spec_wasted} recomputed on CPU)")
+                f"({self.poa_spec_wasted} recomputed on CPU, "
+                f"{int(self.metrics.value('poa_spec_skipped'))} "
+                f"left to the stage)")
 
         # resume from journaled checkpoints (r17): a restarted daemon
         # replays the dead incarnation's committed megabatches into
